@@ -34,6 +34,8 @@ def main() -> None:
                     help="serve the sketch-head benchmark from quantized "
                          "count-array storage (DESIGN.md §12)")
     args = ap.parse_args()
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     only = set(filter(None, args.only.split(",")))
     csv_rows = []
 
@@ -138,7 +140,7 @@ def main() -> None:
     if want("roofline"):
         print("== Roofline (from dry-run artifacts, if present) ==")
         from benchmarks import roofline
-        rows = roofline.run("single")
+        rows = roofline.run("single", roofline.DRYRUN_TARGET)
         for r in rows:
             csv_rows.append(
                 (f"roofline/{r['arch']}/{r['shape']}",

@@ -94,17 +94,22 @@ def distill(
             mse = mse + config.alpha_l1 * jnp.mean(jnp.abs(p["alphas"]))
         return mse
 
+    # The data are arguments of the jitted loop: closed over, they would be
+    # baked into the program as constants (760 MB for a 65k-vocab teacher),
+    # too large for the persistent compilation cache to keep.
     @jax.jit
-    def step(carry, key_step):
-        p, o = carry
-        idx = jax.random.randint(key_step, (config.batch_size,), 0, n)
-        xb, yb = train_x[idx], targets[idx]
-        loss, grads = jax.value_and_grad(loss_fn)(p, xb, yb)
-        p, o = _adam_update(p, grads, o, config.lr, config.weight_decay)
-        return (p, o), loss
+    def fit(carry, keys, xs, ys):
+        def step(carry, key_step):
+            p, o = carry
+            idx = jax.random.randint(key_step, (config.batch_size,), 0, n)
+            loss, grads = jax.value_and_grad(loss_fn)(p, xs[idx], ys[idx])
+            p, o = _adam_update(p, grads, o, config.lr, config.weight_decay)
+            return (p, o), loss
+
+        return jax.lax.scan(step, carry, keys)
 
     keys = jax.random.split(k_loop, config.n_steps)
-    (params, opt), losses = jax.lax.scan(step, (params, opt), keys)
+    (params, opt), losses = fit((params, opt), keys, train_x, targets)
     final_loss = float(
         loss_fn(params, train_x[: min(n, 4096)], targets[: min(n, 4096)])
     )

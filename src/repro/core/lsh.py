@@ -34,6 +34,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.common import HASH_PRECISION
+
 # Large primes for the universal rehash that folds K sub-hash integers into a
 # single table index.  Classic Carter–Wegman style mixing.
 _MIX_PRIME = np.int64(2038074743)
@@ -115,7 +117,8 @@ class L2LSH:
         """Return raw integer sub-hash codes with shape ``(..., L, K)``."""
         c = self.config
         # (..., d) @ (L, K, d) -> (..., L, K)
-        proj = jnp.einsum("...d,lkd->...lk", x, params["w"])
+        proj = jnp.einsum("...d,lkd->...lk", x, params["w"],
+                          precision=HASH_PRECISION)
         return jnp.floor((proj + params["b"]) / c.bandwidth).astype(jnp.int32)
 
     def hash(self, params: dict, x: jnp.ndarray) -> jnp.ndarray:
@@ -151,7 +154,8 @@ class SRPLSH:
         return {"w": w}
 
     def subhash(self, params: dict, x: jnp.ndarray) -> jnp.ndarray:
-        proj = jnp.einsum("...d,lkd->...lk", x, params["w"])
+        proj = jnp.einsum("...d,lkd->...lk", x, params["w"],
+                          precision=HASH_PRECISION)
         return (proj >= 0).astype(jnp.int32)
 
     def hash(self, params: dict, x: jnp.ndarray) -> jnp.ndarray:

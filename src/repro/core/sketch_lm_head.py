@@ -38,7 +38,8 @@ import numpy as np
 from repro.core.distill import DistillConfig, distill
 from repro.core.kernel_model import KernelModel, KernelModelConfig
 from repro.core.lsh import L2LSH, LSHConfig
-from repro.kernels.common import pack_int4_rows, unpack_int4_rows
+from repro.kernels.common import (HASH_PRECISION, pack_int4_rows,
+                                  unpack_int4_rows)
 from repro.kernels.fused_decode.ops import fused_decode_logits
 from repro.kernels.lsh_hash.ops import lsh_hash
 from repro.kernels.race_update.ops import race_update
@@ -73,6 +74,12 @@ def distill_head(
                          @ head_table.astype(jnp.float32).T)
     params, metrics = distill(key, teacher, hidden_samples, model, distill_cfg)
     return params, metrics
+
+
+def _transform(hidden: jnp.ndarray, proj: jnp.ndarray) -> jnp.ndarray:
+    """The asymmetric transform q = h·A, at the hash precision."""
+    return jnp.matmul(hidden.astype(jnp.float32), proj,
+                      precision=HASH_PRECISION)
 
 
 def _check_quant(quant: Optional[str]) -> None:
@@ -216,7 +223,7 @@ def refresh_head(head: dict, cfg: SketchHeadConfig, hidden: jnp.ndarray,
     if (alphas is None) == (targets is None):
         raise ValueError("pass exactly one of alphas= (direct fold) / "
                          "targets= (residual fold)")
-    q = hidden.astype(jnp.float32) @ head["proj"]
+    q = _transform(hidden, head["proj"])
     idx = lsh_hash(q, head["w"], head["b"], bandwidth=cfg.bandwidth,
                    n_buckets=cfg.n_buckets, backend=backend)       # (M, L)
     if targets is not None:
@@ -311,14 +318,15 @@ def apply_head(head: dict, hidden: jnp.ndarray, cfg: SketchHeadConfig,
         # — and the (T, B, L) index stack feeds the tenant-aware gather.
         h32 = hidden.astype(jnp.float32)
         idx = jnp.stack([
-            lsh_hash(h32 @ head["proj"][t], head["w"][t], head["b"][t],
+            lsh_hash(_transform(h32, head["proj"][t]), head["w"][t],
+                     head["b"][t],
                      bandwidth=cfg.bandwidth, n_buckets=cfg.n_buckets,
                      backend=kernel_backend)
             for t in range(head["w"].shape[0])])
         return sketch_head_logits(head["array"], idx, scale=scale,
                                   quant=quant, backend=kernel_backend,
                                   mesh=mesh, tenant_ids=tenant_ids)
-    q = hidden.astype(jnp.float32) @ head["proj"]
+    q = _transform(hidden, head["proj"])
     idx = lsh_hash(q, head["w"], head["b"], bandwidth=cfg.bandwidth,
                    n_buckets=cfg.n_buckets, backend=kernel_backend)
     return sketch_head_logits(head["array"], idx, scale=scale, quant=quant,

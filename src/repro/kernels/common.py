@@ -2,7 +2,9 @@
 
 All kernels target TPU (pl.pallas_call + BlockSpec VMEM tiling) and are
 validated on CPU via ``interpret=True`` — the kernel body runs in Python with
-identical semantics.  ``interpret_default()`` flips automatically.
+identical semantics.  ``interpret_default()`` picks interpret mode on the CPU
+backend only; any other non-TPU backend is an error, never a silent
+interpreter.
 """
 
 from __future__ import annotations
@@ -11,9 +13,49 @@ import jax
 import jax.numpy as jnp
 
 
+#: VMEM the double-buffered count-array block of a decode kernel may take:
+#: half of the 16 MiB a v5e kernel gets by default, leaving the rest for the
+#: dequantized working tile, the resident hash bank and the output block.
+#: Compiling for a v5e shows the boundary: the (L=128, R=16) block fits at
+#: 8 MiB double-buffered and runs out of VMEM at 16 MiB, for every count dtype.
+COUNT_BLOCK_VMEM_BYTES = 8 * 2**20
+
+LANES = 128
+
+#: Precision of every hash projection and count contraction, in the kernels
+#: and their oracles alike.  At default precision a TPU contracts f32 operands
+#: in one bf16 pass: projections near a bucket edge would hash elsewhere than
+#: in the f32 oracle, and gathered counts would lose their low bits.  On the
+#: CPU this changes nothing.
+HASH_PRECISION = jax.lax.Precision.HIGHEST
+
+
 def interpret_default() -> bool:
-    """Interpret kernels on any non-TPU backend (this container is CPU)."""
-    return jax.default_backend() != "tpu"
+    """Compile kernels on TPU, interpret them on CPU, refuse anything else."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"Pallas kernels compile for TPU and interpret on CPU; the default "
+        f"JAX backend is {backend!r}")
+
+
+def vocab_tile(block_rows: int, itemsize: int, v: int) -> int:
+    """Vocab tile for a count array whose (rows, Vt) block is double-buffered.
+
+    The largest multiple of 128 lanes that divides V (padded to 128) and
+    keeps two ``block_rows × Vt × itemsize`` blocks inside
+    :data:`COUNT_BLOCK_VMEM_BYTES`.  Dividing V matters: a tile that does not
+    would pad (copy) the whole count array on every call.
+    """
+    vp = round_up(v, LANES)
+    cap = COUNT_BLOCK_VMEM_BYTES // (2 * block_rows * itemsize)
+    tile = max(LANES, min(cap, vp) // LANES * LANES)
+    while vp % tile:
+        tile -= LANES
+    return tile
 
 
 def mesh_axis_size(mesh, name: str) -> int:
@@ -81,14 +123,17 @@ def pack_int4_rows(q: jnp.ndarray) -> jnp.ndarray:
 
 
 def unpack_int4_rows(packed: jnp.ndarray, n_rows: int) -> jnp.ndarray:
-    """Inverse of :func:`pack_int4_rows`: (⌈N/2⌉, …) bytes → (n_rows, …) int8.
+    """Inverse of :func:`pack_int4_rows`: (⌈N/2⌉, …) bytes → (n_rows, …) int32.
 
-    Sign-extends each nibble ((x << 4) >> 4 arithmetic-shift trick, all in
-    int8 registers) and interleaves low/high back to row order; ``n_rows``
-    slices off the pad row of an odd-N pack.  Cheap enough to run inside a
-    kernel body per tile — the dequantized values never touch HBM.
+    Widens the bytes to int32 first (Mosaic has no int8 shifts), then
+    sign-extends each nibble with the (x << 28) >> 28 arithmetic-shift trick
+    and interleaves low/high back to row order; ``n_rows`` slices off the pad
+    row of an odd-N pack.  The values are exact, returned as int32.  Cheap
+    enough to run inside a kernel body per tile — the dequantized values
+    never touch HBM.
     """
-    lo = jnp.right_shift(jnp.left_shift(packed, 4), 4)
-    hi = jnp.right_shift(packed, 4)
+    x = packed.astype(jnp.int32)
+    lo = jnp.right_shift(jnp.left_shift(x, 28), 28)
+    hi = jnp.right_shift(x, 4)
     rows = jnp.stack([lo, hi], axis=1).reshape(-1, *packed.shape[1:])
     return rows[:n_rows]

@@ -22,6 +22,10 @@ Tiling (DESIGN.md §3):
   sketch: (L, R, Vt)    VMEM  — vocab-tiled exactly like sketch_head
   out:    (Bt, Vt)      VMEM
 
+Vt defaults to ``common.vocab_tile`` for the stored count block: the widest
+tile whose double-buffered (L, R, Vt) block fits the VMEM budget — 512 lanes
+for f32 counts at L=128, R=16, 2048 for int8, 4096 for packed int4.
+
 Steps 1–2 are recomputed per vocab tile: they cost Bt·d·d' + Bt·d'·L·K
 MXU FLOPs — orders of magnitude below the step-3 gather contraction — and
 recomputation is what lets the index tensor live entirely in registers/VMEM
@@ -42,8 +46,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.common import (interpret_default, pad_axis,
-                                  unpack_int4_rows)
+from repro.kernels.common import (HASH_PRECISION, interpret_default,
+                                  pad_axis, unpack_int4_rows, vocab_tile)
 from repro.kernels.lsh_hash.kernel import _mix_codes
 
 
@@ -63,11 +67,13 @@ def _fused_decode_kernel(h_ref, a_ref, w_ref, b_ref, salt_ref, sketch_ref,
 
     # 1. asymmetric transform (MXU).
     q = jax.lax.dot_general(
-        h, a, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        h, a, (((1,), (0,)), ((), ())), precision=HASH_PRECISION,
+        preferred_element_type=jnp.float32,
     )                                     # (Bt, d')
     # 2. hash projection (MXU) + quantize + K-fold rehash (VPU).
     proj = jax.lax.dot_general(
-        q, w, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        q, w, (((1,), (1,)), ((), ())), precision=HASH_PRECISION,
+        preferred_element_type=jnp.float32,
     )                                     # (Bt, L*K)
     codes = jnp.floor((proj + b) / bandwidth).astype(jnp.int32).astype(jnp.uint32)
     codes = codes.reshape(bt, n_rows, k)
@@ -88,7 +94,7 @@ def _fused_decode_kernel(h_ref, a_ref, w_ref, b_ref, salt_ref, sketch_ref,
         onehot = onehot * scale[None, :, :]
     out_ref[...] = jax.lax.dot_general(
         onehot.reshape(bt, l * r), vals.reshape(l * r, vt),
-        (((1,), (0,)), ((), ())),
+        (((1,), (0,)), ((), ())), precision=HASH_PRECISION,
         preferred_element_type=jnp.float32,
     ) * (1.0 / l)
 
@@ -105,7 +111,7 @@ def fused_decode_pallas(
     scale: jnp.ndarray | None = None,      # (L, R) f32 when quantized
     quant: str | None = None,              # None | "int8" | "int4"
     block_b: int = 8,
-    block_v: int = 2048,
+    block_v: int | None = None,            # None: common.vocab_tile
     interpret: bool | None = None,
     row_salt: jnp.ndarray | None = None,   # (L,) uint32 global-row fold salts
 ) -> jnp.ndarray:            # (B, V) f32 logits
@@ -115,6 +121,8 @@ def fused_decode_pallas(
     d_proj = proj.shape[1]
     n_rows, k, _ = w.shape
     l_store, r, v = sketch.shape
+    if block_v is None:
+        block_v = vocab_tile(l_store * r, sketch.dtype.itemsize, v)
 
     w2 = w.reshape(n_rows * k, d_proj)
     b2 = b.reshape(1, n_rows * k)

@@ -17,6 +17,7 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
+from repro.kernels.common import HASH_PRECISION
 from repro.kernels.lsh_hash.ref import lsh_hash_ref
 from repro.kernels.sketch_head.ref import sketch_head_ref
 
@@ -33,6 +34,7 @@ def fused_decode_ref(
     scale: jnp.ndarray | None = None,      # (L, R) f32 when quantized
     quant: str | None = None,              # None | "int8" | "int4"
 ) -> jnp.ndarray:            # (B, V)
-    q = hidden.astype(jnp.float32) @ proj
+    q = jnp.matmul(hidden.astype(jnp.float32), proj,
+                   precision=HASH_PRECISION)
     idx = lsh_hash_ref(q, w, b, bandwidth, n_buckets, row_salt=row_salt)
     return sketch_head_ref(sketch, idx, scale, quant)

@@ -25,7 +25,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.common import interpret_default, pad_axis, round_up
+from repro.kernels.common import (HASH_PRECISION, interpret_default,
+                                  pad_axis, round_up)
 
 _MIX_A = 1103515245
 
@@ -57,7 +58,8 @@ def _lsh_hash_kernel(x_ref, w_ref, b_ref, out_ref, *, k: int, n_buckets: int,
     b = b_ref[...]                       # (1, L*K)
     # MXU: (Bt, d) @ (d, L*K)
     proj = jax.lax.dot_general(
-        x, w, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        x, w, (((1,), (1,)), ((), ())), precision=HASH_PRECISION,
+        preferred_element_type=jnp.float32,
     )                                    # (Bt, L*K)
     codes = jnp.floor((proj + b) / bandwidth).astype(jnp.int32).astype(jnp.uint32)
     codes = codes.reshape(codes.shape[0], n_rows, k)

@@ -15,6 +15,7 @@ from __future__ import annotations
 import jax.numpy as jnp
 
 from repro.core.lsh import _fold_subhashes
+from repro.kernels.common import HASH_PRECISION
 
 
 def lsh_hash_ref(
@@ -25,6 +26,7 @@ def lsh_hash_ref(
     n_buckets: int,
     row_salt: jnp.ndarray | None = None,  # (L,) uint32 global-row fold salts
 ) -> jnp.ndarray:        # (B, L) int32
-    proj = jnp.einsum("bd,lkd->blk", x, w)
+    proj = jnp.einsum("bd,lkd->blk", x, w,
+                      precision=HASH_PRECISION)
     codes = jnp.floor((proj + b) / bandwidth).astype(jnp.int32)
     return _fold_subhashes(codes, n_buckets, salt=row_salt)

@@ -11,8 +11,10 @@ left operand has exactly L nonzeros.  VMEM tiling:
 
   grid = (B / Bt, V / Vt)
   idx:    (Bt, L)       VMEM
-  sketch: (L, R, Vt)    VMEM  — vocab-tiled; with L=64, R=16, Vt=2048 this is
-                               64·16·2048·4 B = 8 MB ≤ VMEM; shrink Vt to fit.
+  sketch: (L, R, Vt)    VMEM  — vocab-tiled; Vt defaults to
+                               ``common.vocab_tile``, the widest tile whose
+                               double-buffered block fits the VMEM budget
+                               (512 lanes for f32 at L=128, R=16).
   out:    (Bt, Vt)      VMEM
 
 Quantized storage (DESIGN.md §12): with ``quant`` set, HBM holds the count
@@ -35,7 +37,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.common import interpret_default, pad_axis, unpack_int4_rows
+from repro.kernels.common import (HASH_PRECISION, interpret_default,
+                                  pad_axis, unpack_int4_rows, vocab_tile)
 
 
 def _sketch_head_kernel(idx_ref, sketch_ref, *rest, quant=None):
@@ -47,7 +50,7 @@ def _sketch_head_kernel(idx_ref, sketch_ref, *rest, quant=None):
     if quant is not None:
         scale = rest[0][...]    # (L, R) f32
         if quant == "int4":
-            vals = unpack_int4_rows(vals, l)      # nibbles → (L, R, Vt) int8
+            vals = unpack_int4_rows(vals, l)      # nibbles → (L, R, Vt) int32
         vals = vals.astype(jnp.float32)
     r, vt = vals.shape[1], vals.shape[2]
 
@@ -61,7 +64,7 @@ def _sketch_head_kernel(idx_ref, sketch_ref, *rest, quant=None):
     # MXU: (Bt, L·R) @ (L·R, Vt) — the row-mean over L reads.
     out_ref[...] = jax.lax.dot_general(
         onehot.reshape(bt, l * r), vals.reshape(l * r, vt),
-        (((1,), (0,)), ((), ())),
+        (((1,), (0,)), ((), ())), precision=HASH_PRECISION,
         preferred_element_type=jnp.float32,
     ) * (1.0 / l)
 
@@ -73,7 +76,7 @@ def sketch_head_pallas(
     *,
     quant: str | None = None,           # None | "int8" | "int4"
     block_b: int = 8,
-    block_v: int = 2048,
+    block_v: int | None = None,         # None: common.vocab_tile
     interpret: bool | None = None,
 ) -> jnp.ndarray:            # (B, V)
     if interpret is None:
@@ -81,6 +84,8 @@ def sketch_head_pallas(
     l = idx.shape[1]
     l_store, r, v = sketch.shape
     n_batch = idx.shape[0]
+    if block_v is None:
+        block_v = vocab_tile(l_store * r, sketch.dtype.itemsize, v)
 
     idxp = pad_axis(idx, 0, block_b)
     sketchp = pad_axis(sketch, 2, block_v)

@@ -22,7 +22,6 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.kernels import registry
@@ -52,7 +51,7 @@ def sketch_head_logits(
     scale: Optional[jnp.ndarray] = None,   # (L, R) f32 when quantized
     quant: Optional[str] = None,           # None | "int8" | "int4"
     block_b: int = 8,
-    block_v: int = 2048,
+    block_v: Optional[int] = None,
     use_pallas: Optional[bool] = None,
     backend: Optional[str] = None,
     mesh=None,
@@ -68,7 +67,8 @@ def sketch_head_logits(
       scale: (L, R) f32 per-row dequantization scales (required iff
         ``quant`` is set).
       quant: ``None`` (f32 counts), ``"int8"`` or ``"int4"`` — static.
-      block_b / block_v: pallas VMEM tile sizes.
+      block_b / block_v: pallas VMEM tile sizes (``block_v=None`` sizes the
+        vocab tile from the VMEM budget, ``common.vocab_tile``).
       use_pallas: deprecated pallas/ref switch (prefer ``backend``).
       backend: kernel registry backend (``"pallas"`` / ``"ref"``); ``None``
         resolves through the registry default.
@@ -134,11 +134,11 @@ def sketch_head_logits(
                         P("model", None))
             operands = (sketch, idx, scale)
 
-        # check_rep=False: pallas_call has no replication rule; the psum
+        # check_vma=False: pallas_call has no replication rule; the psum
         # makes the output replicated over model by construction.
-        return shard_map(
+        return jax.shard_map(
             local, mesh=mesh,
             in_specs=in_specs,
-            out_specs=P(bspec, None), check_rep=False)(*operands)
+            out_specs=P(bspec, None), check_vma=False)(*operands)
     return impl(sketch, idx, scale, quant=quant,
                 block_b=block_b, block_v=block_v)

@@ -132,10 +132,6 @@ def run_cell(arch: str, shape: str, mesh_kind: str, *, smoke: bool = False,
 
         mem = compiled.memory_analysis()
         cost = compiled.cost_analysis()
-        # jax ≥ 0.4.30 returns one properties dict; older versions wrapped it
-        # in a per-device list.
-        if isinstance(cost, (list, tuple)):
-            cost = cost[0] if cost else None
         hlo = compiled.as_text()
 
     hl = analyze(hlo)

@@ -8,20 +8,31 @@ this module never touches jax device state — required for the dry-run's
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes):
+    """A mesh whose axes the partitioner may shard freely (``Auto``).
+
+    ``jax.make_mesh`` defaults to ``Explicit`` axes, under which
+    ``with_sharding_constraint`` and the implicitly sharded gathers of the
+    model code are refused; every mesh of this repo is an ``Auto`` mesh.
+    """
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16×16 single-pod (256 chips) or 2×16×16 two-pod (512 chips) mesh."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(model: int = 1):
     """Degenerate mesh over however many local devices exist (tests/smoke)."""
     n = len(jax.devices())
     assert n % model == 0
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return _auto_mesh((n // model, model), ("data", "model"))
 
 
 def place_serving_state(params, head, mesh):
@@ -85,4 +96,4 @@ def parse_mesh(spec):
             f"mesh {spec!r} needs {data * model} devices but only {n} "
             f"are visible; set XLA_FLAGS=--xla_force_host_platform_"
             f"device_count={data * model} for a forced-CPU mesh")
-    return jax.make_mesh((data, model), ("data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))

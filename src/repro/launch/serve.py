@@ -282,6 +282,11 @@ def build_tenant_heads(params, cfg, n_tenants: int,
     return spec, heads
 
 
+def _device_name() -> str:
+    dev = jax.devices()[0]
+    return f"{len(jax.devices())}x {dev.platform}/{dev.device_kind}"
+
+
 def run_engine(lm, args, sampler: Sampler, head_cache=None) -> None:
     """Serve a synthetic request stream through the continuous-batching
     engine: staggered arrivals, skewed generation lengths, recycled slots.
@@ -319,8 +324,8 @@ def run_engine(lm, args, sampler: Sampler, head_cache=None) -> None:
     n_generated = sum(len(v) for v in finished.values())
     print(f"arch={lm.cfg.name} head={lm.head.describe()} engine served "
           f"{len(finished)} requests over {args.batch} slots: "
-          f"{n_generated} tokens in {dur:.1f}s "
-          f"({n_generated / dur:.1f} tok/s incl. compile), "
+          f"{n_generated} tokens in {dur:.1f}s wall clock on "
+          f"{_device_name()}, compiles included (not a speed), "
           f"{engine.stats['decode_steps']} decode steps in "
           f"{engine.stats['megasteps']} dispatches (chunk "
           f"{engine.decode_chunk}), "
@@ -368,6 +373,7 @@ def run_engine(lm, args, sampler: Sampler, head_cache=None) -> None:
 
 def main() -> None:
     from repro.api.lm import LM
+    from repro.launch.compile_cache import use_compile_cache
 
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="rwkv6-1.6b")
@@ -458,6 +464,7 @@ def main() -> None:
                      "tenant bindings mid-draft)")
     backend = "two_kernel" if args.no_fused else args.backend
 
+    use_compile_cache()
     cfg = get_config(args.arch, smoke=args.smoke)
     params = init_model(jax.random.PRNGKey(0), cfg)
     if args.quant and not args.sketch_head:
@@ -506,8 +513,8 @@ def main() -> None:
     dur = time.time() - t0
     total_tokens = args.batch * (args.prompt_len + args.gen)
     print(f"arch={cfg.name} head={lm.head.describe()} served {args.batch} "
-          f"seqs, {total_tokens} tokens in {dur:.1f}s "
-          f"({total_tokens / dur:.1f} tok/s incl. compile)")
+          f"seqs, {total_tokens} tokens in {dur:.1f}s wall clock on "
+          f"{_device_name()}, compiles included (not a speed)")
     if stats is not None:
         print(f"speculative: K={args.spec_decode}, "
               f"{stats['verify_calls']} verify calls, acceptance "

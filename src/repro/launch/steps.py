@@ -394,11 +394,19 @@ def jitted_serve_fns(cfg: ModelConfig, head: Optional[LogitHead] = None,
 
 @functools.lru_cache(maxsize=None)
 def _jitted_serve_fns(cfg: ModelConfig, head: LogitHead, mesh=None):
+    prefill, insert, reset = _jitted_head_free_fns(cfg, mesh)
+    decode = jax.jit(functools.partial(serve_step, cfg=cfg, head=head,
+                                       mesh=mesh), donate_argnums=(1,))
+    return ServeFns(prefill, decode, insert, reset)
+
+
+@functools.lru_cache(maxsize=None)
+def _jitted_head_free_fns(cfg: ModelConfig, mesh=None):
+    """Prefill and the slot ops never meet the logit head: one compile per
+    (cfg, mesh), shared by every head served over that backbone."""
     from repro.models.model import cache_slot_insert, cache_slot_reset
 
     prefill = jax.jit(functools.partial(prefill_step, cfg=cfg, mesh=mesh))
-    decode = jax.jit(functools.partial(serve_step, cfg=cfg, head=head,
-                                       mesh=mesh), donate_argnums=(1,))
 
     def slot_op(fn):
         def op(pool, *args):
@@ -406,9 +414,7 @@ def _jitted_serve_fns(cfg: ModelConfig, head: LogitHead, mesh=None):
             return out if mesh is None else _constrain_cache(out, mesh)
         return jax.jit(op, donate_argnums=(0,))
 
-    insert = slot_op(cache_slot_insert)
-    reset = slot_op(cache_slot_reset)
-    return ServeFns(prefill, decode, insert, reset)
+    return prefill, slot_op(cache_slot_insert), slot_op(cache_slot_reset)
 
 
 @functools.lru_cache(maxsize=None)

@@ -171,3 +171,13 @@ def test_version_mismatch_rejected():
     record["schema_version"] = SCHEMA_VERSION - 1
     with pytest.raises(ValueError, match="schema_version"):
         validate_serve_record(record)
+
+
+def test_roofline_peaks_are_keyed_by_device_kind():
+    """Peaks come from a named chip; an unknown kind is an error, never a
+    default."""
+    from benchmarks import roofline
+
+    assert roofline.peaks(roofline.DRYRUN_TARGET)["source"]
+    with pytest.raises(KeyError, match="no published peaks"):
+        roofline.peaks("cpu")

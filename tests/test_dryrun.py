@@ -4,6 +4,7 @@ Runs in subprocesses because the 512-placeholder-device XLA flag must be set
 before jax initializes (the main pytest process keeps 1 device)."""
 
 import json
+import os
 import subprocess
 import sys
 from pathlib import Path
@@ -18,7 +19,7 @@ def _run(arch, shape, mesh):
            "--shape", shape, "--mesh", mesh, "--smoke"]
     return subprocess.run(
         cmd, cwd=ROOT, capture_output=True, text=True, timeout=900,
-        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin:/usr/local/bin"})
+        env={**os.environ, "PYTHONPATH": "src"})
 
 
 @pytest.mark.slow

@@ -258,3 +258,39 @@ def test_flash_attention_pallas_vs_ref_explicit(s, win, cap, bq, bk, dtype):
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32),
                                rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("backend,interpret", [("tpu", False), ("cpu", True)])
+def test_interpret_default_follows_the_backend(monkeypatch, backend,
+                                               interpret):
+    from repro.kernels import common
+
+    monkeypatch.setattr(common.jax, "default_backend", lambda: backend)
+    assert common.interpret_default() is interpret
+
+
+def test_interpret_default_refuses_other_backends(monkeypatch):
+    """No silent interpreter on an accelerator the kernels were not
+    written for."""
+    from repro.kernels import common
+
+    monkeypatch.setattr(common.jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="'gpu'"):
+        common.interpret_default()
+
+
+@pytest.mark.parametrize("rows,itemsize,v,tile", [
+    (128 * 16, 4, 65536, 512),     # f32 counts at L=128, R=16
+    (128 * 16, 1, 65536, 2048),    # int8
+    (64 * 16, 1, 65536, 4096),     # int4: L/2 packed rows
+    (8 * 4, 4, 300, 384),          # small head: the whole (padded) vocab
+    (128 * 16, 4, 1100, 384),      # V pads to 1152 = 3 x 384, not 512s
+])
+def test_vocab_tile_fits_the_budget_and_divides_v(rows, itemsize, v, tile):
+    from repro.kernels.common import (COUNT_BLOCK_VMEM_BYTES, LANES,
+                                      round_up, vocab_tile)
+
+    got = vocab_tile(rows, itemsize, v)
+    assert got == tile
+    assert got % LANES == 0 and round_up(v, LANES) % got == 0
+    assert got == LANES or 2 * rows * got * itemsize <= COUNT_BLOCK_VMEM_BYTES

@@ -80,16 +80,15 @@ def test_error_feedback_is_lossless_in_sum():
 def test_compressed_psum_single_device_mesh():
     """compressed_psum under shard_map on a 1-device mesh (degenerate axis)."""
     from functools import partial
-    from jax.experimental.shard_map import shard_map
-    from jax.sharding import Mesh, PartitionSpec as P
+    from jax.sharding import PartitionSpec as P
     from repro.optim.compress import compressed_psum
 
     mesh = jax.make_mesh((1,), ("data",))
     g = {"w": jnp.linspace(-1, 1, 32)}
     e = init_error_feedback(g)
 
-    @partial(shard_map, mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()),
-             check_rep=False)
+    @partial(jax.shard_map, mesh=mesh, in_specs=(P(), P()),
+             out_specs=(P(), P()), check_vma=False)
     def f(gt, et):
         return compressed_psum(gt, et, "data")
 
