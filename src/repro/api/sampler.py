@@ -130,6 +130,7 @@ def _filter_logits(sampler: Sampler, logits: jnp.ndarray) -> jnp.ndarray:
     return logits
 
 
+@jax.named_scope("sample")
 def _sample_impl(sampler: Sampler, key: jax.Array, logits: jnp.ndarray):
     if sampler.is_greedy:
         return key, jnp.argmax(logits, axis=-1).astype(jnp.int32)
@@ -143,5 +144,9 @@ def _sample_impl(sampler: Sampler, key: jax.Array, logits: jnp.ndarray):
 
 @functools.lru_cache(maxsize=None)
 def _jitted_sample(sampler: Sampler):
-    """One compiled sampler per distinct Sampler spec (hashable memo key)."""
-    return jax.jit(functools.partial(_sample_impl, sampler))
+    """One compiled sampler per distinct Sampler spec (hashable memo key),
+    the program ``jit_sample``."""
+    def sample(key, logits):
+        return _sample_impl(sampler, key, logits)
+
+    return jax.jit(sample)

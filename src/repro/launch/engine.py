@@ -47,10 +47,11 @@ from typing import Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.api.heads import DenseHead, LogitHead
 from repro.api.sampler import Sampler
-from repro.launch.steps import (jitted_serve_fns,
+from repro.launch.steps import (fresh_cache_fn, jitted_serve_fns,
                                 resolve_legacy_serving_kwargs)
 from repro.models.config import ModelConfig, SketchHeadConfig
 from repro.models.model import init_decode_cache
@@ -191,6 +192,7 @@ class EngineBackend:
         (self._prefill, self._decode, self._insert,
          self._reset) = jitted_serve_fns(cfg, self.head.without_params(),
                                          mesh=mesh)
+        self._fresh = fresh_cache_fn(cfg, mesh)
 
     def _place_cache(self, cache):
         if self.mesh is None:
@@ -203,16 +205,15 @@ class EngineBackend:
 
     def prefill(self, prompts: jnp.ndarray, max_seq: int):
         """Bulk-prefill (G, P) prompts into a fresh cache → (logits, cache)."""
-        fresh = self._place_cache(
-            init_decode_cache(self.cfg, prompts.shape[0], max_seq))
+        fresh = self._fresh(prompts.shape[0], max_seq)
         logits, filled = self._prefill(self.params, prompts, cache=fresh)
         return np.asarray(logits), filled
 
     def insert(self, pool, filled, slots: np.ndarray):
-        return self._insert(pool, filled, jnp.asarray(slots, jnp.int32))
+        return self._insert(pool, filled, np.asarray(slots, np.int32))
 
     def reset(self, pool, slots: np.ndarray):
-        return self._reset(pool, jnp.asarray(slots, jnp.int32))
+        return self._reset(pool, np.asarray(slots, np.int32))
 
     def decode(self, pool, tokens: np.ndarray, pos: np.ndarray,
                active: np.ndarray, head_params=None):
@@ -221,11 +222,13 @@ class EngineBackend:
         slot binding here each tick)."""
         if head_params is None:
             head_params = self.head.params
-        logits, pool = self._decode(
-            self.params, pool, jnp.asarray(tokens[:, None], jnp.int32),
-            jnp.asarray(pos, jnp.int32), head_params=head_params,
-            active=jnp.asarray(active))
-        return np.asarray(logits), pool
+        with TraceAnnotation("engine.decode"):
+            logits, pool = self._decode(
+                self.params, pool, jnp.asarray(tokens[:, None], jnp.int32),
+                jnp.asarray(pos, jnp.int32), head_params=head_params,
+                active=jnp.asarray(active))
+        with TraceAnnotation("engine.fetch"):
+            return np.asarray(logits), pool
 
     # -- paged pool (DESIGN.md §13) ----------------------------------------
 
@@ -270,30 +273,32 @@ class EngineBackend:
         if head_params is None:
             head_params = self.head.params
         fns = self._paged_fns(max_seq, page_size)
-        pt = jnp.asarray(table, jnp.int32)
-        posj = jnp.asarray(pos, jnp.int32)
-        view = fns.gather(pages, pt)
-        full = merge_paged_view(self.cfg, view, state)
-        logits, new_full = self._decode(
-            self.params, full, jnp.asarray(tokens[:, None], jnp.int32),
-            posj, head_params=head_params, active=jnp.asarray(active))
-        new_pages = fns.commit(pages, new_full, pt, posj)
-        new_state = extract_paged_state(self.cfg, new_full)
-        return np.asarray(logits), new_pages, new_state
+        with TraceAnnotation("engine.decode"):
+            pt = jnp.asarray(table, jnp.int32)
+            posj = jnp.asarray(pos, jnp.int32)
+            view = fns.gather(pages, pt)
+            full = merge_paged_view(self.cfg, view, state)
+            logits, new_full = self._decode(
+                self.params, full, jnp.asarray(tokens[:, None], jnp.int32),
+                posj, head_params=head_params, active=jnp.asarray(active))
+            new_pages = fns.commit(pages, new_full, pt, posj)
+            new_state = extract_paged_state(self.cfg, new_full)
+        with TraceAnnotation("engine.fetch"):
+            return np.asarray(logits), new_pages, new_state
 
     def paged_insert(self, pages, filled, pt_rows: np.ndarray, *,
                      max_seq: int, page_size: int):
         """Scatter freshly prefilled rows into newly mapped pages (``pages``
         donated; ``filled`` is also read by the state insert — not donated)."""
         fns = self._paged_fns(max_seq, page_size)
-        return fns.insert(pages, filled, jnp.asarray(pt_rows, jnp.int32))
+        return fns.insert(pages, filled, np.asarray(pt_rows, np.int32))
 
     def page_copy(self, pages, src_ids: np.ndarray, dst_ids: np.ndarray, *,
                   max_seq: int, page_size: int):
         """COW fork: copy pages ``src_ids → dst_ids`` in every arena."""
         fns = self._paged_fns(max_seq, page_size)
-        return fns.page_copy(pages, jnp.asarray(src_ids, jnp.int32),
-                             jnp.asarray(dst_ids, jnp.int32))
+        return fns.page_copy(pages, np.asarray(src_ids, np.int32),
+                             np.asarray(dst_ids, np.int32))
 
     def state_rows(self, filled, row: int):
         """One request's recurrent-state rows as a host numpy tree — what a
@@ -308,13 +313,13 @@ class EngineBackend:
     def state_restore(self, state, entry_state, slot: int):
         """Insert a prefix entry's stored recurrent rows into one slot."""
         src = jax.tree.map(jnp.asarray, entry_state)
-        return self._insert(state, src, jnp.asarray([slot], jnp.int32))
+        return self._insert(state, src, np.asarray([slot], np.int32))
 
     def expand_rows(self, filled, inv: np.ndarray):
         """Expand a deduped prefill — (G_unique, …) rows → (G, …) via the
         inverse index — so slot inserts stay one-row-per-request."""
         from repro.launch.steps import expand_rows_fn
-        return expand_rows_fn(self.cfg)(filled, jnp.asarray(inv, jnp.int32))
+        return expand_rows_fn(self.cfg)(filled, np.asarray(inv, np.int32))
 
     def megastep(self, pool, tokens: np.ndarray, pos: np.ndarray,
                  active: np.ndarray, key, k: int, sampler: Sampler,
@@ -328,14 +333,16 @@ class EngineBackend:
             head_params = self.head.params
         fn = jitted_megastep(self.cfg, self.head.without_params(), sampler,
                              k, mesh=self.mesh, eos_id=eos_id, masked=True)
-        block, pool, last_tok, pos, active, key = fn(
-            self.params, pool, jnp.asarray(tokens, jnp.int32),
-            jnp.asarray(pos, jnp.int32), key,
-            head_params=head_params, active=jnp.asarray(active))
+        with TraceAnnotation("engine.decode"):
+            block, pool, last_tok, pos, active, key = fn(
+                self.params, pool, jnp.asarray(tokens, jnp.int32),
+                jnp.asarray(pos, jnp.int32), key,
+                head_params=head_params, active=jnp.asarray(active))
         # np.array (not asarray): the engine mutates pos/last_tok per slot
         # on admission, and zero-copy views of jax arrays are read-only.
-        return (np.asarray(block), pool, np.array(last_tok, np.int32),
-                np.array(pos, np.int32), np.asarray(active), key)
+        with TraceAnnotation("engine.fetch"):
+            return (np.asarray(block), pool, np.array(last_tok, np.int32),
+                    np.array(pos, np.int32), np.asarray(active), key)
 
     def spec_megastep(self, pool, tokens: np.ndarray, pos: np.ndarray,
                       active: np.ndarray, key, k: int, sampler: Sampler,
@@ -350,13 +357,15 @@ class EngineBackend:
         fn = jitted_spec_megastep(self.cfg, self.head.without_params(),
                                   sampler, k, mesh=self.mesh, eos_id=eos_id,
                                   masked=True)
-        block, m, acc, _adv, pool, last_tok, pos, active, key = fn(
-            self.params, pool, jnp.asarray(tokens, jnp.int32),
-            jnp.asarray(pos, jnp.int32), key,
-            head_params=self.head.params, active=jnp.asarray(active))
-        return (np.asarray(block), int(jax.device_get(m)), np.asarray(acc),
-                pool, np.array(last_tok, np.int32), np.array(pos, np.int32),
-                np.asarray(active), key)
+        with TraceAnnotation("engine.decode"):
+            block, m, acc, _adv, pool, last_tok, pos, active, key = fn(
+                self.params, pool, jnp.asarray(tokens, jnp.int32),
+                jnp.asarray(pos, jnp.int32), key,
+                head_params=self.head.params, active=jnp.asarray(active))
+        with TraceAnnotation("engine.fetch"):
+            return (np.asarray(block), int(jax.device_get(m)),
+                    np.asarray(acc), pool, np.array(last_tok, np.int32),
+                    np.array(pos, np.int32), np.asarray(active), key)
 
 
 class ServeEngine:
@@ -552,7 +561,8 @@ class ServeEngine:
                     rows.append(r.prompt)
                 inv.append(uniq[key])
             prompts = jnp.asarray(np.stack(rows))
-            logits, filled = self.backend.prefill(prompts, self.max_seq)
+            with TraceAnnotation("engine.prefill"):
+                logits, filled = self.backend.prefill(prompts, self.max_seq)
             if len(rows) < len(group):
                 inv_arr = np.asarray(inv)
                 logits = logits[inv_arr]
@@ -562,7 +572,8 @@ class ServeEngine:
                 self.stats["dedup_saved"] += len(group) - len(rows)
             # ONE sample over the full (G, V) group — the sampler splits its
             # key once per call, so deduping must not change the call count.
-            first = self._sample(logits)
+            with TraceAnnotation("engine.sample_first"):
+                first = self._sample(logits)
             slots = np.asarray([self.sched.admit(r.rid) for r in group])
             self._bind_tenants(group, slots)
             # A slot freed by an immediate retirement earlier in this same
@@ -571,7 +582,8 @@ class ServeEngine:
             # reset would clobber the new request's cache at end of tick.
             self._pending_reset = [s for s in self._pending_reset
                                    if s not in slots]
-            self.pool = self.backend.insert(self.pool, filled, slots)
+            with TraceAnnotation("engine.insert"):
+                self.pool = self.backend.insert(self.pool, filled, slots)
             self.stats["prefill_batches"] += 1
             self._finish_admit(group, slots, first, plen)
 
@@ -604,13 +616,16 @@ class ServeEngine:
             logits_u = filled = None
             if miss_rows:
                 prompts = jnp.asarray(np.stack(miss_rows))
-                logits_u, filled = self.backend.prefill(prompts, self.max_seq)
+                with TraceAnnotation("engine.prefill"):
+                    logits_u, filled = self.backend.prefill(prompts,
+                                                            self.max_seq)
                 self.stats["prefill_batches"] += 1
             # ONE sample per group over rows assembled in arrival order
             # (stored-entry logits for hits, fresh prefill rows otherwise).
-            first = self._sample(np.stack(
-                [p[3].logits if p[1] == "hit" else logits_u[p[3]]
-                 for p in plans]))
+            with TraceAnnotation("engine.sample_first"):
+                first = self._sample(np.stack(
+                    [p[3].logits if p[1] == "hit" else logits_u[p[3]]
+                     for p in plans]))
             slots = np.asarray([self.sched.admit(r.rid) for r in group])
             self._bind_tenants(group, slots)
             self._pending_reset = [s for s in self._pending_reset
@@ -626,31 +641,32 @@ class ServeEngine:
                 self.page_pool.map_slot(int(slot), ids, owned=True)
                 miss_slots.append(int(slot))
                 miss_pt.append(self.page_pool.table[int(slot)].copy())
-            if miss_slots:
-                self.pages = self.backend.paged_insert(
-                    self.pages, filled, np.stack(miss_pt),
-                    max_seq=self.max_seq, page_size=self.page_size)
-                if self._has_state:
-                    self.state = self.backend.insert(
-                        self.state, filled, np.asarray(miss_slots))
+            with TraceAnnotation("engine.insert"):
+                if miss_slots:
+                    self.pages = self.backend.paged_insert(
+                        self.pages, filled, np.stack(miss_pt),
+                        max_seq=self.max_seq, page_size=self.page_size)
+                    if self._has_state:
+                        self.state = self.backend.insert(
+                            self.state, filled, np.asarray(miss_slots))
+                    for p, slot in zip(plans, slots):
+                        if p[1] == "miss":
+                            self.prefix.register(
+                                p[2], self.page_pool.slot_pages(int(slot)),
+                                self.backend.state_rows(filled, p[3]),
+                                logits_u[p[3]], plen)
+                # Hits and dups share the entry's pages (refcounted → COW on
+                # first divergent decode write) and restore its state rows.
                 for p, slot in zip(plans, slots):
                     if p[1] == "miss":
-                        self.prefix.register(
-                            p[2], self.page_pool.slot_pages(int(slot)),
-                            self.backend.state_rows(filled, p[3]),
-                            logits_u[p[3]], plen)
-            # Hits and dups share the entry's pages (refcounted → COW on
-            # first divergent decode write) and restore its state rows.
-            for p, slot in zip(plans, slots):
-                if p[1] == "miss":
-                    continue
-                entry = (p[3] if p[1] == "hit"
-                         else self.prefix.peek(p[2]))
-                self.page_pool.map_slot(int(slot), entry.page_ids,
-                                        owned=False)
-                if entry.state is not None:
-                    self.state = self.backend.state_restore(
-                        self.state, entry.state, int(slot))
+                        continue
+                    entry = (p[3] if p[1] == "hit"
+                             else self.prefix.peek(p[2]))
+                    self.page_pool.map_slot(int(slot), entry.page_ids,
+                                            owned=False)
+                    if entry.state is not None:
+                        self.state = self.backend.state_restore(
+                            self.state, entry.state, int(slot))
             self._finish_admit(group, slots, first, plen)
         self._sync_page_stats()
 
@@ -812,8 +828,16 @@ class ServeEngine:
             block = self._emulate_megastep(active, chunk)
         self.stats["decode_steps"] += chunk
         self.stats["megasteps"] += 1
+        with TraceAnnotation("engine.emit"):
+            self._emit(active_slots, block, chunk)
+
+    def _emit(self, active_slots: List[int], block: np.ndarray,
+              rows: int) -> None:
+        """Append the first ``rows`` tokens of each active slot's column of
+        ``block`` to its request, retiring it at its budget or on EOS (a
+        retired row's later entries are padding)."""
         for s in active_slots:
-            for i in range(chunk):
+            for i in range(rows):
                 tok = int(block[i, s])
                 self.outputs[self.sched.owner[s]].append(tok)
                 self.remaining[s] -= 1
@@ -842,16 +866,8 @@ class ServeEngine:
         self.stats["verify_calls"] += 1
         self.stats["draft_tokens"] += draft_k * len(active_slots)
         self.stats["accepted_draft_tokens"] += int(acc[active_slots].sum())
-        for s in active_slots:
-            for i in range(m):
-                tok = int(block[i, s])
-                self.outputs[self.sched.owner[s]].append(tok)
-                self.remaining[s] -= 1
-                self.stats["active_slot_steps"] += 1
-                if (self.remaining[s] == 0
-                        or (self.eos_id is not None and tok == self.eos_id)):
-                    self._retire(s)
-                    break
+        with TraceAnnotation("engine.emit"):
+            self._emit(active_slots, block, m)
         return m
 
     def _emulate_megastep(self, active: np.ndarray, chunk: int) -> np.ndarray:
@@ -878,8 +894,15 @@ class ServeEngine:
     def step(self) -> None:
         """One tick: admit into free slots, then decode every occupied slot
         — one token (``decode_chunk=1``, the bitwise-parity default) or a
-        ``decode_chunk``-clamped megastep block."""
-        self._admit()
+        ``decode_chunk``-clamped megastep block.
+
+        Each phase is a ``jax.profiler.TraceAnnotation`` span (``engine.admit``
+        holding ``engine.prefill`` / ``engine.sample_first`` /
+        ``engine.insert``; ``engine.decode``, ``engine.fetch``,
+        ``engine.emit``, ``engine.reset``): free unless a profiler trace is
+        running, and then on the device trace's clock (DESIGN.md §15)."""
+        with TraceAnnotation("engine.admit"):
+            self._admit()
         active_slots = self.sched.active_slots()
         advanced = 1
         if active_slots and self.spec_decode:
@@ -894,7 +917,8 @@ class ServeEngine:
             hp = self._head_params_now()
             kw = {} if hp is None else {"head_params": hp}
             if self.paged:
-                self._ensure_write_pages(active_slots)
+                with TraceAnnotation("engine.decode"):
+                    self._ensure_write_pages(active_slots)
                 logits, self.pages, self.state = self.backend.paged_decode(
                     self.pages, self.state, self.page_pool.table,
                     self.last_tok, self.pos, active,
@@ -902,38 +926,46 @@ class ServeEngine:
             else:
                 logits, self.pool = self.backend.decode(
                     self.pool, self.last_tok, self.pos, active, **kw)
-            nxt = self._sample(logits)
+            with TraceAnnotation("engine.sample"):
+                nxt = self._sample(logits)
             self.stats["decode_steps"] += 1
             self.stats["megasteps"] += 1
             self.stats["active_slot_steps"] += len(active_slots)
-            for s in active_slots:
-                tok = int(nxt[s])
-                self.outputs[self.sched.owner[s]].append(tok)
-                self.pos[s] += 1
-                self.last_tok[s] = tok
-                self.remaining[s] -= 1
-                if (self.remaining[s] == 0
-                        or (self.eos_id is not None and tok == self.eos_id)):
-                    self._retire(s)
+            with TraceAnnotation("engine.emit"):
+                for s in active_slots:
+                    tok = int(nxt[s])
+                    self.outputs[self.sched.owner[s]].append(tok)
+                    self.pos[s] += 1
+                    self.last_tok[s] = tok
+                    self.remaining[s] -= 1
+                    if (self.remaining[s] == 0
+                            or (self.eos_id is not None
+                                and tok == self.eos_id)):
+                        self._retire(s)
         if self._pending_reset:
-            # Pad to a fixed (n_slots,) shape so the jitted reset compiles
-            # once; duplicate indices write the same zeros, so padding with
-            # the first slot is a no-op.
-            slots = self._pending_reset + [self._pending_reset[0]] * (
-                self.n_slots - len(self._pending_reset))
-            if self.paged:
-                # Pages were unmapped at retirement (the arena needs no
-                # zeroing — unmapped gathers read the reserved zero page);
-                # only the recurrent state rows are zeroed.
-                if self._has_state:
-                    self.state = self.backend.reset(self.state,
-                                                    np.asarray(slots))
-            else:
-                self.pool = self.backend.reset(self.pool, np.asarray(slots))
-            self._pending_reset.clear()
+            with TraceAnnotation("engine.reset"):
+                self._reset_pending()
         if self.paged:
             self._sync_page_stats()
         self.now += advanced
+
+    def _reset_pending(self) -> None:
+        """Zero the rows of every slot retired this tick, in one dispatch."""
+        # Pad to a fixed (n_slots,) shape so the jitted reset compiles
+        # once; duplicate indices write the same zeros, so padding with
+        # the first slot is a no-op.
+        slots = self._pending_reset + [self._pending_reset[0]] * (
+            self.n_slots - len(self._pending_reset))
+        if self.paged:
+            # Pages were unmapped at retirement (the arena needs no
+            # zeroing — unmapped gathers read the reserved zero page);
+            # only the recurrent state rows are zeroed.
+            if self._has_state:
+                self.state = self.backend.reset(self.state,
+                                                np.asarray(slots))
+        else:
+            self.pool = self.backend.reset(self.pool, np.asarray(slots))
+        self._pending_reset.clear()
 
     def run(self) -> Dict[int, List[int]]:
         """Tick until the queue drains and every slot retires."""

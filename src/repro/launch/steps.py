@@ -225,9 +225,10 @@ def serve_step(params, cache, tokens, pos, cfg: ModelConfig,
                                         encoder_states=encoder_states,
                                         return_hidden=True)
         if head.needs_hidden:
-            logits = head.apply(head_params, hidden, mesh=mesh)
-            if cfg.final_logit_softcap:
-                logits = softcap(logits, cfg.final_logit_softcap)
+            with jax.named_scope("head"):
+                logits = head.apply(head_params, hidden, mesh=mesh)
+                if cfg.final_logit_softcap:
+                    logits = softcap(logits, cfg.final_logit_softcap)
         else:
             from repro.models.model import dense_verify_logits
             logits = dense_verify_logits(params, hidden, cfg)
@@ -392,12 +393,21 @@ def jitted_serve_fns(cfg: ModelConfig, head: Optional[LogitHead] = None,
                                           masked=True))
 
 
+# Every jitted serving program is a named function, never a bare
+# ``functools.partial``: its name is the program's (``jit_<name>``) in the
+# HLO, on a device trace's ``XLA Modules`` line and in xprof, where a
+# partial would show as ``jit__unknown`` (DESIGN.md §15).
+
 @functools.lru_cache(maxsize=None)
 def _jitted_serve_fns(cfg: ModelConfig, head: LogitHead, mesh=None):
     prefill, insert, reset = _jitted_head_free_fns(cfg, mesh)
-    decode = jax.jit(functools.partial(serve_step, cfg=cfg, head=head,
-                                       mesh=mesh), donate_argnums=(1,))
-    return ServeFns(prefill, decode, insert, reset)
+
+    def decode(params, cache, tokens, pos, **kw):
+        return serve_step(params, cache, tokens, pos, cfg, head=head,
+                          mesh=mesh, **kw)
+
+    return ServeFns(prefill, jax.jit(decode, donate_argnums=(1,)), insert,
+                    reset)
 
 
 @functools.lru_cache(maxsize=None)
@@ -406,15 +416,32 @@ def _jitted_head_free_fns(cfg: ModelConfig, mesh=None):
     (cfg, mesh), shared by every head served over that backbone."""
     from repro.models.model import cache_slot_insert, cache_slot_reset
 
-    prefill = jax.jit(functools.partial(prefill_step, cfg=cfg, mesh=mesh))
+    def constrain(cache):
+        return cache if mesh is None else _constrain_cache(cache, mesh)
 
-    def slot_op(fn):
-        def op(pool, *args):
-            out = fn(cfg, pool, *args)
-            return out if mesh is None else _constrain_cache(out, mesh)
-        return jax.jit(op, donate_argnums=(0,))
+    def prefill(params, tokens, **kw):
+        return prefill_step(params, tokens, cfg, mesh=mesh, **kw)
 
-    return prefill, slot_op(cache_slot_insert), slot_op(cache_slot_reset)
+    def slot_insert(pool, src, slots):
+        return constrain(cache_slot_insert(cfg, pool, src, slots))
+
+    def slot_reset(pool, slots):
+        return constrain(cache_slot_reset(cfg, pool, slots))
+
+    return (jax.jit(prefill), jax.jit(slot_insert, donate_argnums=(0,)),
+            jax.jit(slot_reset, donate_argnums=(0,)))
+
+
+@functools.lru_cache(maxsize=None)
+def fresh_cache_fn(cfg: ModelConfig, mesh=None):
+    """Jitted ``fresh_cache(batch, max_seq)``: an empty decode cache for a
+    prefill batch, made on the device in one program (on a mesh, in the
+    serving cache shardings)."""
+    def fresh_cache(batch, max_seq):
+        cache = init_decode_cache(cfg, batch, max_seq)
+        return cache if mesh is None else _constrain_cache(cache, mesh)
+
+    return jax.jit(fresh_cache, static_argnums=(0, 1))
 
 
 @functools.lru_cache(maxsize=None)
@@ -466,7 +493,11 @@ def expand_rows_fn(cfg: ModelConfig):
     """Jitted ``model.cache_expand_rows`` for one config (admission dedupe:
     expand a deduped prefill's cache rows back to one per request)."""
     from repro.models.model import cache_expand_rows
-    return jax.jit(functools.partial(cache_expand_rows, cfg))
+
+    def expand_rows(cache, inv):
+        return cache_expand_rows(cfg, cache, inv)
+
+    return jax.jit(expand_rows)
 
 
 # --------------------------------------------------------------------------

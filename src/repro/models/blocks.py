@@ -257,31 +257,18 @@ def apply_layer(
     x = constrain(x, "dp", seq, None)
     h = rms_norm(x, params["norm1"], eps)
 
-    if kind in ("attn", "attn_local", "attn_global"):
-        delta, new_cache = attn_mod.attention(
-            params["mixer"], h, positions, _attn_cfg(cfg, kind),
-            cache=cache, cache_pos=cache_pos)
-    elif kind == "xattn":
-        delta, new_cache = attn_mod.attention(
-            params["mixer"], h, positions, _attn_cfg(cfg, kind),
-            kv_source=encoder_states)
-    elif kind == "mla":
-        delta, new_cache = mla_mod.mla_attention(
-            params["mixer"], h, positions, cfg.mla,
-            cache=cache, cache_pos=cache_pos)
-    elif kind == "mamba":
-        delta, new_cache = mamba_mod.mamba_block(
-            params["mixer"], h, cfg.mamba, cache=cache)
-    elif kind == "rwkv":
+    if kind == "rwkv":
         prev = cache.tm_prev if cache is not None else None
         st = cache.state if cache is not None else None
-        delta, tm_last, new_state = rwkv_mod.rwkv_time_mix(
-            params["mixer"], h, prev=prev, state0=st)
+        with jax.named_scope("mixer"):
+            delta, tm_last, new_state = rwkv_mod.rwkv_time_mix(
+                params["mixer"], h, prev=prev, state0=st)
         x = x + delta
         h2 = rms_norm(x, params["norm2"], eps)
         cm_prev = cache.cm_prev if cache is not None else None
-        delta2, cm_last = rwkv_mod.rwkv_channel_mix(
-            params["mixer"], h2, prev=cm_prev)
+        with jax.named_scope("ffn"):
+            delta2, cm_last = rwkv_mod.rwkv_channel_mix(
+                params["mixer"], h2, prev=cm_prev)
         new_cache = None
         if cache is not None:
             new_cache = rwkv_mod.RWKVCache(
@@ -289,21 +276,39 @@ def apply_layer(
                 cm_last.astype(cache.cm_prev.dtype),
                 new_state.astype(cache.state.dtype))
         return x + delta2, new_cache, aux
-    else:
-        raise ValueError(kind)
+
+    with jax.named_scope("mixer"):
+        if kind in ("attn", "attn_local", "attn_global"):
+            delta, new_cache = attn_mod.attention(
+                params["mixer"], h, positions, _attn_cfg(cfg, kind),
+                cache=cache, cache_pos=cache_pos)
+        elif kind == "xattn":
+            delta, new_cache = attn_mod.attention(
+                params["mixer"], h, positions, _attn_cfg(cfg, kind),
+                kv_source=encoder_states)
+        elif kind == "mla":
+            delta, new_cache = mla_mod.mla_attention(
+                params["mixer"], h, positions, cfg.mla,
+                cache=cache, cache_pos=cache_pos)
+        elif kind == "mamba":
+            delta, new_cache = mamba_mod.mamba_block(
+                params["mixer"], h, cfg.mamba, cache=cache)
+        else:
+            raise ValueError(kind)
 
     x = x + constrain(delta, "dp", seq, None)
     h2 = rms_norm(x, params["norm2"], eps)
-    if ffn == "moe":
-        delta2, aux = moe_ffn(params["ffn"], h2, cfg.moe)
-    else:
-        # Megatron pattern: d_ff intermediate pinned to TP shards, so the
-        # partitioner emits exactly one AR (after w_down), never a
-        # contraction-sharded d_ff-wide AR.
-        f = params["ffn"]
-        g = jax.nn.silu(jnp.einsum("bsd,df->bsf", h2, f["w_gate"]))
-        u = jnp.einsum("bsd,df->bsf", h2, f["w_up"])
-        g = constrain(g, "dp", None, "tp")
-        u = constrain(u, "dp", None, "tp")
-        delta2 = jnp.einsum("bsf,fd->bsd", g * u, f["w_down"])
+    with jax.named_scope("ffn"):
+        if ffn == "moe":
+            delta2, aux = moe_ffn(params["ffn"], h2, cfg.moe)
+        else:
+            # Megatron pattern: d_ff intermediate pinned to TP shards, so
+            # the partitioner emits exactly one AR (after w_down), never a
+            # contraction-sharded d_ff-wide AR.
+            f = params["ffn"]
+            g = jax.nn.silu(jnp.einsum("bsd,df->bsf", h2, f["w_gate"]))
+            u = jnp.einsum("bsd,df->bsf", h2, f["w_up"])
+            g = constrain(g, "dp", None, "tp")
+            u = constrain(u, "dp", None, "tp")
+            delta2 = jnp.einsum("bsf,fd->bsd", g * u, f["w_down"])
     return x + constrain(delta2, "dp", seq, None), new_cache, aux

@@ -294,6 +294,7 @@ def extract_state_rows(cfg: ModelConfig, cache: dict, row: int) -> dict:
     return out
 
 
+@jax.named_scope("cache_mask")
 def mask_cache_update(cfg: ModelConfig, old: dict, new: dict,
                       active: jnp.ndarray) -> dict:
     """Keep ``new`` cache rows where ``active`` (B,) bool, else ``old``.
@@ -342,6 +343,7 @@ def cache_rollback(cfg: ModelConfig, cache: dict, snap: dict) -> dict:
     return _map_layer_caches(cfg, merge, cache, snap)
 
 
+@jax.named_scope("head")
 def dense_verify_logits(params: dict, hidden: jnp.ndarray,
                         cfg: ModelConfig) -> jnp.ndarray:
     """``forward()``'s dense unembed tail on externally-carried hiddens.
@@ -407,9 +409,10 @@ def forward(
     (repro.core.sketch_lm_head / repro.kernels.fused_decode).
     """
     b, s = tokens.shape
-    x = embed(tokens, params["embed"]) * jnp.asarray(
-        cfg.d_model ** 0.5, jnp.bfloat16)
-    x = constrain(x, "dp", None, None)
+    with jax.named_scope("embed"):
+        x = embed(tokens, params["embed"]) * jnp.asarray(
+            cfg.d_model ** 0.5, jnp.bfloat16)
+        x = constrain(x, "dp", None, None)
     if cache_pos is None:
         positions = jnp.arange(s)
         cache_pos_v = jnp.zeros((), jnp.int32)
@@ -462,11 +465,12 @@ def forward(
     if return_hidden:
         return (x.astype(jnp.float32),
                 (new_cache if cache is not None else None), aux)
-    table = params["embed"] if cfg.tie_embeddings else params["head"]
-    logits = unembed(x, table).astype(jnp.float32)
-    logits = constrain(logits, "dp", None, "tp")  # vocab-parallel logits
-    if cfg.final_logit_softcap:
-        logits = softcap(logits, cfg.final_logit_softcap)
+    with jax.named_scope("head"):
+        table = params["embed"] if cfg.tie_embeddings else params["head"]
+        logits = unembed(x, table).astype(jnp.float32)
+        logits = constrain(logits, "dp", None, "tp")  # vocab-parallel logits
+        if cfg.final_logit_softcap:
+            logits = softcap(logits, cfg.final_logit_softcap)
     return logits, (new_cache if cache is not None else None), aux
 
 
