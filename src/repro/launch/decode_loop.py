@@ -18,8 +18,8 @@ sample — the same (step, sample) sequence and the same key chain as the
 ``for t in range(gen_len)`` loop it replaces, so one seed reproduces the
 same stream at any chunk size.  Rows that emit ``eos_id`` retire in-scan:
 their later block entries hold ``pad_id`` and their cache rows freeze via
-the same ``mask_cache_update`` active-mask discipline the engine uses for
-parked slots.
+the same ``active`` mask the engine uses for parked slots: every layer
+holds back a retired row's cache write (``serve_step``).
 
 Two flavors share one implementation, specialized by the ``pos`` rank:
 

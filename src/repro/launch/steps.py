@@ -197,8 +197,11 @@ def serve_step(params, cache, tokens, pos, cfg: ModelConfig,
 
     Continuous batching: ``pos`` may be per-slot (B,) counters, and
     ``active`` a (B,) bool mask — cache rows of inactive (free/padded) slots
-    are kept bitwise unchanged, so a parked slot neither attends nor decays
-    state while it waits for a new request.
+    are kept bitwise unchanged, so a parked SWA ring does not advance and a
+    parked state does not decay while the slot waits for a new request.
+    Each layer holds back a parked row's write where it writes its cache
+    (one K/V entry, or the new recurrent state); nothing passes over the
+    whole pool.  Callers ignore a parked row's logits.
 
     Sharded serving: ``mesh`` (static; threaded by ``jitted_serve_fns``)
     routes stateful heads through their shard_map path and re-constrains the
@@ -210,20 +213,19 @@ def serve_step(params, cache, tokens, pos, cfg: ModelConfig,
     via ``dense_verify_logits`` on that hidden, bitwise-identical to the
     in-backbone unembed it normally takes.
     """
-    from repro.models.model import mask_cache_update
-
     head, head_params = _resolve_head_shim(head, head_params, sketch_head,
                                            sketch_cfg, fused)
     hidden = None
     if not head.needs_hidden and not return_hidden:
         logits, new_cache = decode_step(params, cache, tokens, pos, cfg,
-                                        encoder_states=encoder_states)
+                                        encoder_states=encoder_states,
+                                        active=active)
     else:
         from repro.models.layers import softcap
 
         hidden, new_cache = decode_step(params, cache, tokens, pos, cfg,
                                         encoder_states=encoder_states,
-                                        return_hidden=True)
+                                        active=active, return_hidden=True)
         if head.needs_hidden:
             with jax.named_scope("head"):
                 logits = head.apply(head_params, hidden, mesh=mesh)
@@ -232,8 +234,6 @@ def serve_step(params, cache, tokens, pos, cfg: ModelConfig,
         else:
             from repro.models.model import dense_verify_logits
             logits = dense_verify_logits(params, hidden, cfg)
-    if active is not None:
-        new_cache = mask_cache_update(cfg, cache, new_cache, active)
     if mesh is not None:
         new_cache = _constrain_cache(new_cache, mesh)
     if return_hidden:

@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.models.config import AttentionConfig
-from repro.models.layers import apply_rope, init_dense, softcap
+from repro.models.layers import apply_rope, cache_write, init_dense, softcap
 from repro.sharding.ctx import constrain, logical_axis_size
 
 _CHUNK_THRESHOLD = 8192
@@ -276,8 +276,12 @@ def attention(
     kv_source: Optional[jnp.ndarray] = None,   # encoder states for cross-attn
     cache: Optional[KVCache] = None,
     cache_pos: Optional[jnp.ndarray] = None,   # scalar: #tokens already cached
+    active: Optional[jnp.ndarray] = None,      # (B,) decode rows to write
 ) -> Tuple[jnp.ndarray, Optional[KVCache]]:
-    """Full attention block. Returns (output, updated_cache)."""
+    """Full attention block. Returns (output, updated_cache).
+
+    At decode, rows clear in ``active`` leave their cache entry as it was
+    (``layers.cache_write``)."""
     b, s, _ = x.shape
     src = kv_source if kv_source is not None else x
     # Query heads pinned to TP shards (head-parallel attention); KV heads
@@ -360,9 +364,8 @@ def attention(
         size = cache.k.shape[1]
         ring = bool(cfg.window) and cfg.window <= size
         slot = cache_pos % size if ring else cache_pos      # (B,)
-        bi = jnp.arange(b)
-        ck = cache.k.at[bi, slot].set(k[:, 0].astype(cache.k.dtype))
-        cv = cache.v.at[bi, slot].set(v[:, 0].astype(cache.v.dtype))
+        ck = cache_write(cache.k, k, slot, active)
+        cv = cache_write(cache.v, v, slot, active)
         new_cache = KVCache(ck, cv)
         i = jnp.arange(size)[None, :]
         if ring:
@@ -381,10 +384,8 @@ def attention(
             slot = cache_pos % size  # rolling ring buffer for SWA
         else:
             slot = cache_pos
-        ck = jax.lax.dynamic_update_slice(cache.k, k.astype(cache.k.dtype),
-                                          (0, slot, 0, 0))
-        cv = jax.lax.dynamic_update_slice(cache.v, v.astype(cache.v.dtype),
-                                          (0, slot, 0, 0))
+        ck = cache_write(cache.k, k, slot, active)
+        cv = cache_write(cache.v, v, slot, active)
         new_cache = KVCache(ck, cv)
         k_all, v_all = ck, cv
         if cfg.window and cfg.window <= size:

@@ -20,7 +20,7 @@ from repro.models import mamba as mamba_mod
 from repro.models import mla as mla_mod
 from repro.models import rwkv as rwkv_mod
 from repro.models.config import ModelConfig
-from repro.models.layers import init_dense, rms_norm
+from repro.models.layers import hold_parked, init_dense, rms_norm
 from repro.models.moe import init_moe, moe_ffn
 from repro.sharding.ctx import constrain
 
@@ -242,8 +242,12 @@ def apply_layer(
     encoder_states: Optional[jnp.ndarray] = None,
     cache: Any = None,
     cache_pos: Optional[jnp.ndarray] = None,
+    active: Optional[jnp.ndarray] = None,
 ) -> Tuple[jnp.ndarray, Any, jnp.ndarray]:
-    """Apply one layer. Returns (x, new_cache, aux_loss)."""
+    """Apply one layer. Returns (x, new_cache, aux_loss).
+
+    ``active`` (B,) bool, at decode: rows left clear keep their cache
+    bitwise unchanged — each family holds back its own write."""
     eps = cfg.norm_eps
     aux = jnp.zeros((), jnp.float32)
     # Sequence parallelism (Korthikanti et al.): the residual stream — and
@@ -271,17 +275,16 @@ def apply_layer(
                 params["mixer"], h2, prev=cm_prev)
         new_cache = None
         if cache is not None:
-            new_cache = rwkv_mod.RWKVCache(
-                tm_last.astype(cache.tm_prev.dtype),
-                cm_last.astype(cache.cm_prev.dtype),
-                new_state.astype(cache.state.dtype))
+            new_cache = jax.tree.map(
+                lambda n, o: hold_parked(active, n.astype(o.dtype), o),
+                rwkv_mod.RWKVCache(tm_last, cm_last, new_state), cache)
         return x + delta2, new_cache, aux
 
     with jax.named_scope("mixer"):
         if kind in ("attn", "attn_local", "attn_global"):
             delta, new_cache = attn_mod.attention(
                 params["mixer"], h, positions, _attn_cfg(cfg, kind),
-                cache=cache, cache_pos=cache_pos)
+                cache=cache, cache_pos=cache_pos, active=active)
         elif kind == "xattn":
             delta, new_cache = attn_mod.attention(
                 params["mixer"], h, positions, _attn_cfg(cfg, kind),
@@ -289,10 +292,10 @@ def apply_layer(
         elif kind == "mla":
             delta, new_cache = mla_mod.mla_attention(
                 params["mixer"], h, positions, cfg.mla,
-                cache=cache, cache_pos=cache_pos)
+                cache=cache, cache_pos=cache_pos, active=active)
         elif kind == "mamba":
             delta, new_cache = mamba_mod.mamba_block(
-                params["mixer"], h, cfg.mamba, cache=cache)
+                params["mixer"], h, cfg.mamba, cache=cache, active=active)
         else:
             raise ValueError(kind)
 

@@ -29,6 +29,41 @@ def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndar
     return out.astype(x.dtype)
 
 
+def hold_parked(active, new: jnp.ndarray, old: jnp.ndarray) -> jnp.ndarray:
+    """``new`` where the (B,) ``active`` mask is set, else ``old``.
+
+    A layer applies it to exactly what its decode step writes (one K/V entry
+    per row, or the new recurrent state), so a parked slot's cache stays
+    bitwise unchanged without a pass over the whole pool.  ``active=None``
+    writes every row."""
+    if active is None:
+        return new
+    with jax.named_scope("cache_mask"):
+        return jnp.where(active.reshape((-1,) + (1,) * (new.ndim - 1)),
+                         new, old)
+
+
+def cache_write(buf: jnp.ndarray, new: jnp.ndarray, pos,
+                active=None) -> jnp.ndarray:
+    """Write decode entries ``new`` (B, S, ...) into a (B, T, ...) cache.
+
+    ``pos`` is one offset shared by every row, or (B,) per-row positions
+    (continuous batching, S == 1).  Rows clear in the (B,) ``active`` mask
+    keep what ``buf`` held at the written positions (``hold_parked``)."""
+    new = new.astype(buf.dtype)
+    if jnp.ndim(pos) == 1:
+        bi = jnp.arange(buf.shape[0])
+        new = new[:, 0]
+        if active is not None:
+            new = hold_parked(active, new, buf[bi, pos])
+        return buf.at[bi, pos].set(new)
+    at = (0, pos) + (0,) * (buf.ndim - 2)
+    if active is not None:
+        new = hold_parked(active, new,
+                          jax.lax.dynamic_slice(buf, at, new.shape))
+    return jax.lax.dynamic_update_slice(buf, new, at)
+
+
 def softcap(x: jnp.ndarray, cap: float) -> jnp.ndarray:
     """Gemma-2 style logit soft-capping: cap·tanh(x/cap)."""
     return cap * jnp.tanh(x / cap)

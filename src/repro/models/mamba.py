@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.models.config import MambaConfig
-from repro.models.layers import init_dense
+from repro.models.layers import hold_parked, init_dense
 
 _SCAN_CHUNK = 256
 
@@ -105,7 +105,10 @@ def mamba_block(
     cfg: MambaConfig,
     *,
     cache: Optional[MambaCache] = None,
+    active: Optional[jnp.ndarray] = None,    # (B,) decode rows to advance
 ) -> Tuple[jnp.ndarray, Optional[MambaCache]]:
+    """Selective-SSM block.  Returns (output, updated_cache); rows clear in
+    ``active`` keep their conv window and SSM state unchanged."""
     b, s, d_model = x.shape
     d_in = cfg.expand * d_model
     r = _dt_rank(d_model, cfg)
@@ -178,6 +181,9 @@ def mamba_block(
     y = (y * jax.nn.silu(z.astype(jnp.float32))).astype(x.dtype)
     out = jnp.einsum("bsi,id->bsd", y, params["out_proj"])
 
-    new_cache = (MambaCache(new_conv, new_ssm.astype(cache.ssm.dtype))
-                 if cache is not None else None)
+    new_cache = None
+    if cache is not None:
+        new_cache = MambaCache(
+            hold_parked(active, new_conv, cache.conv),
+            hold_parked(active, new_ssm.astype(cache.ssm.dtype), cache.ssm))
     return out, new_cache

@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.models.config import MLAConfig
-from repro.models.layers import apply_rope, init_dense
+from repro.models.layers import apply_rope, cache_write, init_dense
 
 
 class MLACache(NamedTuple):
@@ -131,6 +131,7 @@ def mla_attention(
     rope_theta: float = 10000.0,
     cache: Optional[MLACache] = None,
     cache_pos: Optional[jnp.ndarray] = None,
+    active: Optional[jnp.ndarray] = None,    # (B,) decode rows to write
 ) -> Tuple[jnp.ndarray, Optional[MLACache]]:
     b, s, d = x.shape
     h = cfg.n_heads
@@ -173,36 +174,21 @@ def mla_attention(
         o = o[..., : cfg.v_head_dim].reshape(b, s, h * cfg.v_head_dim)
         return jnp.einsum("bse,ed->bsd", o, params["w_o"]), None
 
-    new_cache = None
-    if cache is not None and jnp.ndim(cache_pos) == 1:
-        # Per-slot decode (continuous-batching engine): each sequence owns a
-        # cache row with its own position counter; single-token steps only.
-        if s != 1:
-            raise NotImplementedError(
-                "per-slot cache_pos supports single-token decode only; "
-                "prefill into a fresh cache and slot_insert it instead")
-        bi = jnp.arange(b)
-        ck = cache.c_kv.at[bi, cache_pos].set(
-            c_kv[:, 0].astype(cache.c_kv.dtype))
-        cr = cache.k_rope.at[bi, cache_pos].set(
-            k_rope[:, 0].astype(cache.k_rope.dtype))
-        new_cache = MLACache(ck, cr)
-        c_all, r_all = ck, cr
-        k_pos = jnp.arange(c_all.shape[1])[None, :]          # (1, T)
-        k_pos = jnp.where(k_pos < cache_pos[:, None] + 1, k_pos,
-                          jnp.iinfo(jnp.int32).max)          # (B, T)
-    elif cache is not None:
-        ck = jax.lax.dynamic_update_slice(
-            cache.c_kv, c_kv.astype(cache.c_kv.dtype), (0, cache_pos, 0))
-        cr = jax.lax.dynamic_update_slice(
-            cache.k_rope, k_rope.astype(cache.k_rope.dtype), (0, cache_pos, 0))
-        new_cache = MLACache(ck, cr)
-        c_all, r_all = ck, cr
-        k_pos = jnp.arange(c_all.shape[1])
-        k_pos = jnp.where(k_pos < cache_pos + s, k_pos, jnp.iinfo(jnp.int32).max)
-    else:
-        c_all, r_all = c_kv, k_rope
-        k_pos = positions
+    # Decode against the latent cache.  Per-slot cache_pos (continuous-
+    # batching engine): each sequence owns a cache row with its own position
+    # counter; single-token steps only.
+    per_slot = jnp.ndim(cache_pos) == 1
+    if per_slot and s != 1:
+        raise NotImplementedError(
+            "per-slot cache_pos supports single-token decode only; "
+            "prefill into a fresh cache and slot_insert it instead")
+    c_all = cache_write(cache.c_kv, c_kv, cache_pos, active)
+    r_all = cache_write(cache.k_rope, k_rope, cache_pos, active)
+    new_cache = MLACache(c_all, r_all)
+    end = (cache_pos[:, None] if per_slot else cache_pos) + s
+    k_pos = jnp.arange(c_all.shape[1])
+    k_pos = jnp.where(k_pos < end, k_pos,
+                      jnp.iinfo(jnp.int32).max)              # (T,) or (B, T)
 
     # Absorption: fold W_uk into the query → attend over the latent directly.
     w_uk = params["w_uk"].reshape(cfg.kv_lora_rank, h, cfg.qk_nope_head_dim)

@@ -294,23 +294,6 @@ def extract_state_rows(cfg: ModelConfig, cache: dict, row: int) -> dict:
     return out
 
 
-@jax.named_scope("cache_mask")
-def mask_cache_update(cfg: ModelConfig, old: dict, new: dict,
-                      active: jnp.ndarray) -> dict:
-    """Keep ``new`` cache rows where ``active`` (B,) bool, else ``old``.
-
-    Free/padded slots of a continuous-batching decode step keep their cache
-    bitwise unchanged — a parked SWA ring doesn't advance, a parked SSM/WKV
-    state doesn't decay.
-    """
-    def merge(kind, o, n):
-        sel = lambda a, b: jnp.where(
-            active.reshape((-1,) + (1,) * (a.ndim - 1)), b, a)
-        return jax.tree.map(sel, o, n)
-
-    return _map_layer_caches(cfg, merge, old, new)
-
-
 def cache_snapshot(cfg: ModelConfig, cache: dict) -> dict:
     """The per-step rollback state speculative decode must keep (§11).
 
@@ -374,7 +357,7 @@ def dense_verify_logits(params: dict, hidden: jnp.ndarray,
 # --------------------------------------------------------------------------
 
 def _period_body(cfg: ModelConfig, x, positions, period_params, period_cache,
-                 encoder_states, cache_pos):
+                 encoder_states, cache_pos, active):
     """Apply one period (all pattern positions). Returns (x, new_cache, aux)."""
     aux = jnp.zeros((), jnp.float32)
     new_cache = {}
@@ -384,7 +367,7 @@ def _period_body(cfg: ModelConfig, x, positions, period_params, period_cache,
         x, nc, a = blocks.apply_layer(
             period_params[f"pos{j}"], x, positions, cfg, kind, ffn,
             encoder_states=encoder_states, cache=layer_cache,
-            cache_pos=cache_pos)
+            cache_pos=cache_pos, active=active)
         new_cache[f"pos{j}"] = nc
         aux = aux + a
     return x, new_cache, aux
@@ -398,10 +381,15 @@ def forward(
     encoder_states: Optional[jnp.ndarray] = None,   # (B, T_enc, d) stub frontend
     cache: Optional[dict] = None,
     cache_pos: Optional[jnp.ndarray] = None,
+    active: Optional[jnp.ndarray] = None,    # (B,) bool: decode rows to write
     remat: bool = True,
     return_hidden: bool = False,
 ) -> Tuple[jnp.ndarray, Optional[dict], jnp.ndarray]:
     """Run the backbone. Returns (logits, new_cache, aux_loss).
+
+    ``active`` masks a decode step's cache writes: every layer holds back
+    the write of a row left clear, so that row's cache comes back bitwise
+    unchanged (``layers.hold_parked``).  ``None`` writes every row.
 
     ``return_hidden=True`` stops after the final norm and returns the
     (B, S, d_model) f32 hidden states in place of logits — the input the
@@ -437,29 +425,37 @@ def forward(
             x, nc, a = blocks.apply_layer(
                 lp, x, positions, cfg, cfg.pattern[0], "dense",
                 encoder_states=encoder_states, cache=pcaches[i],
-                cache_pos=cache_pos_v)
+                cache_pos=cache_pos_v, active=active)
             new_p.append(nc)
             aux = aux + a
         if cache is not None:
             new_cache["prologue"] = new_p
 
-    # Scanned periods.
-    period_cache = (cache or {}).get("periods")
-
+    # Scanned periods.  The stacked cache rides in the carry and each period
+    # writes its own layer back in place: scanned in and out, it would be
+    # two buffers, and XLA would copy the whole pool into the second one
+    # every step.
     def body(carry, scanned):
-        xc, auxc = carry
-        pp, pc = scanned
+        xc, auxc, pcs = carry
+        pp, i = scanned
+        pc = jax.tree.map(
+            lambda c: jax.lax.dynamic_index_in_dim(c, i, keepdims=False), pcs)
         xc, nc, a = _period_body(cfg, xc, positions, pp, pc,
-                                 encoder_states, cache_pos_v)
-        return (xc, auxc + a), nc
+                                 encoder_states, cache_pos_v, active)
+        if pcs is not None:
+            pcs = jax.tree.map(
+                lambda c, n: jax.lax.dynamic_update_index_in_dim(c, n, i, 0),
+                pcs, nc)
+        return (xc, auxc + a, pcs), None
 
     if remat and cache is None:
         body = jax.checkpoint(body)
 
-    (x, aux), scanned_cache = jax.lax.scan(
-        body, (x, aux), (params["periods"], period_cache))
+    (x, aux, period_cache), _ = jax.lax.scan(
+        body, (x, aux, (cache or {}).get("periods")),
+        (params["periods"], jnp.arange(cfg.n_periods)))
     if cache is not None:
-        new_cache["periods"] = scanned_cache
+        new_cache["periods"] = period_cache
 
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     if return_hidden:
@@ -514,16 +510,19 @@ def decode_step(
     cfg: ModelConfig,
     *,
     encoder_states: Optional[jnp.ndarray] = None,
+    active: Optional[jnp.ndarray] = None,
     return_hidden: bool = False,
 ) -> Tuple[jnp.ndarray, dict]:
     """One decode step: returns (logits (B, V), updated cache).
 
-    ``return_hidden=True`` returns the (B, d_model) final hidden instead of
-    logits — the dense unembed is skipped entirely so a sketched head can
-    replace it (the paper's serving hot path).
+    ``active`` (B,) bool keeps the cache rows of parked slots bitwise
+    unchanged (``forward``).  ``return_hidden=True`` returns the
+    (B, d_model) final hidden instead of logits — the dense unembed is
+    skipped entirely so a sketched head can replace it (the paper's serving
+    hot path).
     """
     out, new_cache, _ = forward(
         params, tokens, cfg, encoder_states=encoder_states,
-        cache=cache, cache_pos=pos, remat=False,
+        cache=cache, cache_pos=pos, active=active, remat=False,
         return_hidden=return_hidden)
     return out[:, -1], new_cache

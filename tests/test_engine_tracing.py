@@ -84,6 +84,9 @@ def traced_tick(served, tmp_path_factory):
     log_dir = str(tmp_path_factory.mktemp("trace"))
     jax.profiler.start_trace(log_dir)
     engine.step()
+    # The tick's last program (the slot reset) is dispatched, not awaited:
+    # without the wait it can run after the trace stops.
+    jax.block_until_ready(engine.pool)
     jax.profiler.stop_trace()
     assert not engine.sched.n_active and not len(engine.queue)
 
