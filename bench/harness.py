@@ -217,8 +217,9 @@ class RunView:
 
 
 def reduce_trace(log_dir: str) -> dict:
-    """Busy and idle device time, per-program time and kernel events of the
-    traced span (the ``bench.traced`` host span)."""
+    """Busy and idle device time, per-program time, kernel events and
+    per-program time by model scope of the traced span (the
+    ``bench.traced`` host span)."""
     tr = trace_reduce.load(trace_reduce.find_xplane(log_dir))
     spans = [h for h in tr.host if h[0].startswith(("bench.", "client.",
                                                     "engine."))]
@@ -239,6 +240,8 @@ def reduce_trace(log_dir: str) -> dict:
                 [(trace_reduce.op_label(n), s, e, st)
                  for n, s, e, st in ops], lo, hi),
             "idle": trace_reduce.idle_by_host(tr, dev, lo, hi, spans),
+            "scopes": trace_reduce.scope_time(ops, tr.modules.get(dev, []),
+                                              lo, hi),
         }
     return {"lo": lo, "hi": hi, "window_ns": hi - lo, "devices": per_dev}
 

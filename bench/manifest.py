@@ -9,7 +9,10 @@ Everything is looked up under ``root`` (the checkout):
 * a model family's plain reference at ``bench/reference/<reference>.py``;
 * a metric's reader at ``bench/metrics/<metric>.py``;
 * a cell's output check (the number compared and its limit) at
-  ``bench/checks/<workload>.json``.
+  ``bench/checks/<workload>.json``;
+* a configuration's CPU smoke size (the overrides that cut it, the limit of
+  its smoke runs and why) at ``bench/smoke/<config>.json``, read by the
+  benchmark's own tests (``tests/bench/conftest.py``), never by a run.
 
 A later change adds a configuration, a mix or a metric as new files plus
 manifest entries; no file here needs an edit for it.

@@ -1,5 +1,6 @@
 """Helpers the metric readers share: the first traced device, the device
-time of programs by name, and the program's kernels by name and shape."""
+time of programs by name and by model scope, and the program's kernels by
+name and shape."""
 
 
 def first_device(run):
@@ -15,6 +16,15 @@ def program_ns(dev, needle: str) -> float:
         return 0.0
     return sum(ns for name, ns in dev["modules"].items()
                if needle in name and "spec_" not in name)
+
+
+def scope_ns(dev, program: str, scope: str) -> float:
+    """Device self time of the program ``program`` (e.g. ``jit_megastep``)
+    under the model scope ``scope`` (one component of its operations'
+    ``tf_op`` path, e.g. ``head``)."""
+    if not dev:
+        return 0.0
+    return dev["scopes"].get(program, {}).get(scope, 0.0)
 
 
 def pallas_calls(dev, out_shape: str):

@@ -8,7 +8,7 @@ from pathlib import Path
 import pytest
 
 from bench import costs
-from bench.manifest import load_module
+from bench.manifest import load_manifest, load_module
 
 ROOT = Path(__file__).resolve().parents[2]
 
@@ -57,7 +57,10 @@ def test_granite_q9_has_2_16_billion_params():
     assert round(n / 1e9, 2) == 2.16 and round(2 * n / 1e9, 2) == 4.33
 
 
-@pytest.mark.parametrize("config", [RWKV, GRANITE], ids=lambda c: c["name"])
+@pytest.mark.parametrize(
+    "config", [json.loads((ROOT / c["file"]).read_text())
+               for c in load_manifest(ROOT)["configs"]],
+    ids=lambda c: c["name"])
 def test_param_count_matches_the_weights_made(config):
     import jax
 

@@ -20,57 +20,19 @@ from bench import harness
 
 ROOT = Path(__file__).resolve().parents[2]
 SEED = 5
-# Widest gaps of sound runs at these sizes on the CPU: rwkv 0.0082,
-# granite 0.0035 (seeds 1, 2, 3, 2**31 + 7); the faults below read 0.034 and
-# more (rwkv) and 0.37 and more (granite) on seed 5.
-SMOKE = {
-    "rwkv6-1.6b.sketch-int8": dict(n_layers=2, d_model=64, d_ff=128,
-                                   vocab_size=256, decode_chunk=2),
-    "granite-8b.q9-dense": dict(n_layers=2, d_model=64, d_ff=128,
-                                vocab_size=256, decode_chunk=2,
-                                attention={"n_heads": 4, "n_kv_heads": 2,
-                                           "head_dim": 16,
-                                           "rope_theta": 10000.0},
-                                embed_std=0.02),
-}
-LIMITS = {"rwkv6-1.6b.sketch-int8": 0.02, "granite-8b.q9-dense": 0.05}
-MIX = {"arrival": "backlog", "slots": 4, "max_seq": 48, "admit_per_tick": 1,
-       "prompt_buckets": [8, 16], "prompt_weights": [1, 1],
-       "output": {"dist": "uniform", "min": 8, "max": 24}, "block": 8}
+# The configurations with a smoke size and limit (bench/smoke/<config>.json);
+# test_every_config_has_a_smoke_size names any without one.
+CONFIGS = sorted(
+    c["name"] for c in
+    json.loads((ROOT / "BENCHMARK.json").read_text())["configs"]
+    if (ROOT / "bench/smoke" / f"{c['name']}.json").is_file())
 
 
 @pytest.fixture(scope="module")
-def root(tmp_path_factory):
+def root(tmp_path_factory, make_smoke_checkout):
     """A checkout whose cells are the committed configurations cut to a
     smoke size, under a small backlog."""
-    root = tmp_path_factory.mktemp("checkout")
-    shutil.copytree(ROOT / "bench", root / "bench",
-                    ignore=shutil.ignore_patterns("__pycache__"))
-    man = json.loads((ROOT / "BENCHMARK.json").read_text())
-    (root / "bench/traffic/smoke.json").write_text(json.dumps(MIX))
-    cells = man["workloads"]
-    man["workloads"] = []
-    for c in man["configs"]:
-        cfg = json.loads((ROOT / c["file"]).read_text())
-        cfg.update(SMOKE[c["name"]])
-        cfg["head"] = dict(cfg["head"], n_anchors=64)
-        (root / c["file"]).write_text(json.dumps(cfg))
-        cell = next(w["name"] for w in cells if w["config"] == c["name"])
-        committed = json.loads((ROOT / "bench/checks" / f"{cell}.json")
-                               .read_text())
-        spec = {"limits": {"max_gap": LIMITS[c["name"]]},
-                "controls": committed["controls"]}
-        if "first_tokens" in committed:
-            spec["first_tokens"] = 8
-        (root / "bench/checks" / f"{c['name']}.smoke.json").write_text(
-            json.dumps(spec))
-        man["workloads"].append({"name": f"{c['name']}.smoke",
-                                 "config": c["name"], "traffic": "smoke",
-                                 "chips": 1, "why": "smoke"})
-    for m in man["end_to_end"] + man["per_layer"]:
-        m.pop("workloads", None)
-    (root / "BENCHMARK.json").write_text(json.dumps(man))
-    return root
+    return make_smoke_checkout(ROOT, tmp_path_factory.mktemp("checkout"))
 
 
 def run(root, name, seed=SEED):
@@ -79,7 +41,7 @@ def run(root, name, seed=SEED):
                             cache=False)
 
 
-@pytest.mark.parametrize("name", sorted(SMOKE))
+@pytest.mark.parametrize("name", CONFIGS)
 def test_sound_run_is_correct(root, name):
     out = run(root, name)
     assert out["correct"], out["check"]
@@ -115,7 +77,7 @@ def _half_the_batch(real):
     return megastep
 
 
-@pytest.mark.parametrize("name", sorted(SMOKE))
+@pytest.mark.parametrize("name", CONFIGS)
 @pytest.mark.parametrize("fault", [_stale_state, _altered_token,
                                    _half_the_batch],
                          ids=["state_unchanged", "token_altered",

@@ -38,8 +38,29 @@ def test_every_metric_has_a_reader():
         assert (ROOT / "bench" / "metrics" / f"{m['name']}.py").is_file()
 
 
-def test_new_files_are_found_by_name(tmp_path):
-    before = {p: p.read_bytes() for p in (ROOT / "bench").rglob("*.py")}
+def test_every_config_has_a_smoke_size():
+    """Each configuration names its CPU smoke size, the limit its sound
+    runs there are held to, and why (``tests/bench/test_bench_harness.py``
+    runs every configuration at that size)."""
+    man = manifest.load_manifest(ROOT)
+    for c in man["configs"]:
+        path = ROOT / "bench" / "smoke" / f"{c['name']}.json"
+        assert path.is_file(), f"{c['name']} has no smoke size: add {path}"
+        smoke = json.loads(path.read_text())
+        assert smoke["why"].strip() and smoke["limits"], path
+        assert all(v > 0 for v in smoke["limits"].values()), path
+        cfg = json.loads((ROOT / c["file"]).read_text())
+        assert set(smoke["overrides"]) <= set(cfg), (path, "unknown keys")
+
+
+def _repo_files():
+    return {p: p.read_bytes() for d in ("bench", "tests/bench")
+            for p in (ROOT / d).rglob("*") if p.suffix in (".py", ".json")}
+
+
+def test_new_files_are_found_by_name(tmp_path, make_smoke_checkout):
+    before = _repo_files()
+    before[ROOT / "BENCHMARK.json"] = (ROOT / "BENCHMARK.json").read_bytes()
     root = tmp_path / "checkout"
     shutil.copytree(ROOT / "bench", root / "bench",
                     ignore=shutil.ignore_patterns("__pycache__"))
@@ -88,8 +109,25 @@ def test_new_files_are_found_by_name(tmp_path):
     assert sched.request(0).due_s > 0
     with pytest.raises(KeyError):
         manifest.find_cell("no.such.cell", root)
-    assert before == {p: p.read_bytes()
-                      for p in (ROOT / "bench").rglob("*.py")}
+    # its CPU smoke size: the harness tests' checkout, built from this one,
+    # holds a smoke cell of it beside the committed configurations'
+    smoke = json.loads((ROOT / "bench/smoke/rwkv6-1.6b.sketch-int8.json")
+                       .read_text())
+    smoke["limits"] = {"max_gap": 0.03}
+    (root / "bench/smoke/rwkv6-1.6b.dense.json").write_text(
+        json.dumps(smoke))
+    checkout = make_smoke_checkout(root, tmp_path / "smoke")
+    assert "rwkv6-1.6b.dense.smoke" in {
+        w["name"] for w in manifest.load_manifest(checkout)["workloads"]}
+    small = manifest.find_cell("rwkv6-1.6b.dense.smoke", checkout)
+    assert small["config"]["d_model"] == 64
+    assert small["config"]["head"]["kind"] == "dense"
+    assert small["check"] == {"limits": {"max_gap": 0.03}}
+    assert small["reference"].__file__.endswith("rwkv6_twin.py")
+    assert "answer_len" in small["readers"]
+    after = _repo_files()
+    after[ROOT / "BENCHMARK.json"] = (ROOT / "BENCHMARK.json").read_bytes()
+    assert before == after
 
 
 def test_harness_names_no_model_family():

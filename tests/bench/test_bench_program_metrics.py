@@ -22,7 +22,8 @@ def ev(name, start_ms, end_ms):
     return (name, start_ms * MS, end_ms * MS, {})
 
 
-def run_view(module_events, prompt_tokens=(1000, 2500)):
+def run_view(module_events, prompt_tokens=(1000, 2500), scopes=None,
+             decode_steps=(100, 110)):
     """A traced run whose first device ran ``module_events``; ``modules``
     sums them per program, as ``harness.reduce_trace`` does."""
     modules = {}
@@ -31,10 +32,11 @@ def run_view(module_events, prompt_tokens=(1000, 2500)):
         modules[key] = modules.get(key, 0.0) + e - s
     dev = {"busy_ns": 0.0, "modules": modules,
            "module_events": module_events, "op_events": [], "ops": {},
-           "idle": {}}
+           "idle": {}, "scopes": scopes or {}}
     trace = {"lo": 0.0, "hi": 100 * MS, "window_ns": 100 * MS,
              "devices": {"/device:TPU:0": dev}}
-    counters = tuple({"prompt_tokens": n} for n in prompt_tokens)
+    counters = tuple({"prompt_tokens": n, "decode_steps": k}
+                     for n, k in zip(prompt_tokens, decode_steps))
     return RunView(cfg={}, mix={"slots": 4}, ref=None, records=[],
                    window=(0.0, 0.1), counters=counters, setup_s=1.0,
                    peaks=None, trace=trace)
@@ -77,3 +79,30 @@ def test_nothing_to_read_reads_none(name, view):
 def test_prefill_without_new_prompt_tokens_reads_none():
     view = run_view(NAMED, prompt_tokens=(2500, 2500))
     assert reader("prefill_us_per_token").read(view) is None
+
+
+# Self time by model scope, as ``harness.reduce_trace`` sums it: 10 decode
+# steps in the span.
+SCOPES = {"jit_megastep": {"jit(megastep)": 40 * MS, "head": 9 * MS,
+                           "mixer": 25 * MS, "ffn": 4 * MS},
+          "jit_prefill": {"head": 2 * MS, "mixer": 1 * MS}}
+
+
+@pytest.mark.parametrize("name, ms", [("head_step_ms", 0.9),
+                                      ("mixer_step_ms", 2.5)])
+def test_scope_readers_are_megastep_scope_time_per_step(name, ms):
+    assert reader(name).read(run_view(NAMED, scopes=SCOPES)) == \
+        pytest.approx(ms)
+
+
+@pytest.mark.parametrize("name", ["head_step_ms", "mixer_step_ms"])
+@pytest.mark.parametrize("view", [
+    run_view(NAMED),
+    run_view(NAMED, scopes={"jit_prefill": SCOPES["jit_prefill"]}),
+    run_view(NAMED, scopes=SCOPES, decode_steps=(110, 110)),
+    RunView(cfg={}, mix={}, ref=None, records=[], window=(0.0, 0.1),
+            counters=({"decode_steps": 0}, {"decode_steps": 10}),
+            setup_s=1.0, peaks=None, trace=None),
+], ids=["no_scopes", "prefill_only", "no_decode_step", "untraced"])
+def test_scope_readers_read_none_without_their_scope(name, view):
+    assert reader(name).read(view) is None
